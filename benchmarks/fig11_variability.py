@@ -127,6 +127,8 @@ def adaptation_trace(spec, policy: str, seed: int = 7) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = Rows()
     main(rows=rows)
     main_fleet(rows=rows)

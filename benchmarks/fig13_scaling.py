@@ -55,6 +55,8 @@ def main(quick: bool = False, rows: Rows | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = Rows()
     main(rows=rows)
     rows.emit()

@@ -107,6 +107,8 @@ def main_fleet(quick: bool = False, rows: Rows | None = None) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--backend", default="oracle",
                     choices=("oracle", "fleet", "both"))

@@ -80,4 +80,6 @@ def main(quick: bool = False, rows: Rows | None = None) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print(markdown_table(load_all()))

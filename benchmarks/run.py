@@ -3,13 +3,16 @@
 ``python -m benchmarks.run``          — full runs (≈ paper durations)
 ``python -m benchmarks.run --quick``  — reduced sweep for CI
 
-Prints ``name,us_per_call,derived`` CSV.
+Prints ``name,us_per_call,derived`` CSV.  A figure that raises is
+reported (an ``<name>/ERROR`` row and a line on stderr), the remaining
+figures still run, and the run exits 1.
 """
 from __future__ import annotations
 
 import argparse
 import sys
 import time
+import traceback
 
 from benchmarks.common import Rows
 
@@ -37,7 +40,10 @@ def main() -> None:
         keep = set(args.only.split(","))
         modules = {k: v for k, v in modules.items() if k in keep}
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows = Rows()
+    failed = []
     t0 = time.time()
     for name, mod in modules.items():
         t = time.time()
@@ -45,12 +51,16 @@ def main() -> None:
             mod.main(quick=args.quick, rows=rows)
             rows.add(f"{name}/elapsed_s", (time.time() - t) * 1e6,
                      f"{time.time() - t:.1f}s")
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 - report, run the rest
+            failed.append(name)
             rows.add(f"{name}/ERROR", 0.0, f"{type(e).__name__}: {e}")
+            traceback.print_exc()
             print(f"[benchmark {name} failed: {e}]", file=sys.stderr)
     rows.add("total/elapsed_s", (time.time() - t0) * 1e6,
              f"{time.time() - t0:.1f}s")
     rows.emit()
+    if failed:
+        sys.exit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

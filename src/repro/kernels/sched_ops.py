@@ -15,10 +15,22 @@ ref_masked_argext`, the jnp oracle):
   matching ``jnp.argmax``/``jnp.argmin`` on the filled array);
 * a row with no enabled entry returns ``idx == -1`` and the fill value.
 
-On CPU (this container) the public wrappers trace the jnp reference —
-identical semantics, no interpret-mode overhead in the per-tick hot path;
-``interpret=True`` forces the actual kernel body through the Pallas
-interpreter for equivalence tests.  On TPU they compile to Mosaic.
+``masked_argext`` picks its lowering with ``jax.lax.platform_dependent``:
+a program compiled for a TPU runs the Mosaic kernel, one compiled for any
+other platform traces the jnp reference (identical semantics, no
+interpreter in the per-tick hot path).  The choice follows the platform
+being compiled for, not the process's default backend, so a compile for
+a described TPU contains the kernel.  A program traced under a
+multi-device mesh (``jax.set_mesh``, as the fleet's sharded entry points
+do) selects with the reference: XLA cannot partition a Mosaic call.
+``interpret=True`` forces the kernel body through the Pallas interpreter
+for equivalence tests.
+
+Mosaic tiling: both outputs are ``(rows, 1)`` columns and the row block
+is either every row (up to ``block_b``) or ``block_b`` rows, a multiple
+of 8, so the kernel compiles unbatched at any row count and under the
+``vmap`` the fleet tick applies over edges (which prepends a squeezed
+batch dimension to every block).
 """
 from __future__ import annotations
 
@@ -42,56 +54,44 @@ def _argext_kernel(s_ref, m_ref, idx_ref, val_ref, *, is_max: bool,
                    n: int):
     """One (block_b, Np) tile → per-row (first arg-extremum, value)."""
     fill = NEG if is_max else POS
-    s = s_ref[...].astype(jnp.float32)                       # (bb, Np)
+    s = s_ref[...]                                           # (bb, Np)
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    enabled = m_ref[...] & (cols < n)                        # lane padding
+    enabled = (m_ref[...] != 0) & (cols < n)                 # lane padding
     v = jnp.where(enabled, s, fill)
-    best = v.max(axis=-1) if is_max else v.min(axis=-1)
-    hit = v == best[:, None]
-    first = jnp.where(hit, cols, n).min(axis=-1)
-    idx_ref[...] = jnp.where(enabled.any(axis=-1), first, -1)
+    best = (v.max if is_max else v.min)(axis=-1, keepdims=True)  # (bb, 1)
+    first = jnp.where(v == best, cols, n).min(axis=-1, keepdims=True)
+    some = enabled.astype(jnp.int32).max(axis=-1, keepdims=True) > 0
+    idx_ref[...] = jnp.where(some, first, -1)
     val_ref[...] = best
 
 
 def _pallas_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
                    block_b: int, interpret: bool):
     b, n = scores.shape
+    if b > block_b and block_b % 8:
+        raise ValueError(f"block_b must be a multiple of 8, got {block_b}")
     block_b = min(block_b, b)
     pad_b = (-b) % block_b
     pad_n = (-n) % _LANES
     s = jnp.pad(scores.astype(jnp.float32), ((0, pad_b), (0, pad_n)))
-    m = jnp.pad(mask, ((0, pad_b), (0, pad_n)))
-    np_ = n + pad_n
-    grid = (s.shape[0] // block_b,)
+    m = jnp.pad(mask.astype(jnp.int32), ((0, pad_b), (0, pad_n)))
+    rows, np_ = s.shape
+    col = pl.BlockSpec((block_b, 1), lambda i: (i, 0))
     idx, val = pl.pallas_call(
         functools.partial(_argext_kernel, is_max=is_max, n=n),
-        grid=grid,
+        grid=(rows // block_b,),
         in_specs=[pl.BlockSpec((block_b, np_), lambda i: (i, 0)),
                   pl.BlockSpec((block_b, np_), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((block_b,), lambda i: (i,)),
-                   pl.BlockSpec((block_b,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((s.shape[0],), jnp.int32),
-                   jax.ShapeDtypeStruct((s.shape[0],), jnp.float32)],
+        out_specs=[col, col],
+        out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=interpret,
     )(s, m)
-    return idx[:b], val[:b]
+    return idx[:b, 0], val[:b, 0]
 
 
-def masked_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
-                  block_b: int = DEFAULT_BLOCK_B,
-                  interpret: Optional[bool] = None):
-    """``scores, mask: (..., N)`` → ``(idx (...,), val (...,))``.
-
-    ``interpret=None`` resolves the backend once: the Pallas kernel on
-    TPU, the jnp reference on anything else (so vmapped/scanned hot-path
-    callers never hit the Python interpreter).  ``interpret=True`` runs
-    the kernel body through the Pallas interpreter regardless — the
-    kernel-vs-reference test path.
-    """
-    if interpret is None:
-        if jax.default_backend() != "tpu":
-            return ref.ref_masked_argext(scores, mask, is_max=is_max)
-        interpret = False
+def _kernel_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
+                   block_b: int, interpret: bool):
     lead = scores.shape[:-1]
     n = scores.shape[-1]
     s2 = scores.reshape(-1, n)
@@ -99,6 +99,31 @@ def masked_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
     idx, val = _pallas_argext(s2, m2, is_max=is_max, block_b=block_b,
                               interpret=interpret)
     return idx.reshape(lead), val.reshape(lead)
+
+
+def masked_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
+                  block_b: int = DEFAULT_BLOCK_B,
+                  interpret: Optional[bool] = None):
+    """``scores, mask: (..., N)`` → ``(idx (...,), val (...,))``.
+
+    ``interpret=None`` lowers per platform: the Pallas kernel where the
+    program is compiled for one TPU, the jnp reference elsewhere and in
+    programs traced under a multi-device mesh.
+    ``interpret=True`` runs the kernel body through the Pallas
+    interpreter on any platform — the kernel-vs-reference test path.
+    """
+    kernel = functools.partial(_kernel_argext, is_max=is_max,
+                               block_b=block_b, interpret=bool(interpret))
+    if interpret is not None:
+        return kernel(scores, mask)
+    if jax.sharding.get_abstract_mesh().size > 1:
+        # traced under a multi-device mesh (``jax.set_mesh``): XLA cannot
+        # partition a Mosaic call, so select with the reference, which it
+        # partitions like the rest of the tick
+        return ref.ref_masked_argext(scores, mask, is_max=is_max)
+    return jax.lax.platform_dependent(
+        scores, mask, tpu=kernel,
+        default=functools.partial(ref.ref_masked_argext, is_max=is_max))
 
 
 def masked_argmax(scores, mask, **kw):

@@ -1,4 +1,5 @@
-"""Production mesh construction (single-pod 16×16 and 2-pod 2×16×16).
+"""Mesh construction: the one Auto-axis constructor every mesh goes
+through, and the production (16×16, 2×16×16) and host meshes built on it.
 
 A function, not a module-level constant, so importing this module never
 touches jax device state (device count is locked at first jax init —
@@ -14,22 +15,25 @@ HBM_BW = 819e9                  # B/s
 ICI_BW = 50e9                   # B/s per link
 
 
-def _auto_axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=Auto`` where available; older jax has no AxisType and
-    treats every mesh axis as Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+def make_mesh(shape: tuple[int, ...],
+              axes: tuple[str, ...]) -> jax.sharding.Mesh:
+    """The repo's one mesh constructor: every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to Explicit axes, under which the fleet
+    tick's vmap over a sharded edge axis is refused; with Auto axes the
+    partitioner places the collectives.
+    """
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this process actually has (tests / CPU smoke)."""
-    n = jax.device_count()
-    return jax.make_mesh((n, 1), ("data", "model"), **_auto_axis_kwargs(2))
+    return make_mesh((jax.device_count(), 1), ("data", "model"))

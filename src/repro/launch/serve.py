@@ -31,6 +31,7 @@ from repro.configs.base import reduced
 from repro.configs.registry import ARCHS
 from repro.core.schedulers import ALL_POLICIES, make_policy
 from repro.core.task import ModelProfile
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.engine import ServableModel, ServeEngine, run_stream
 
 
@@ -79,6 +80,7 @@ def build_roles(cloud_concurrency: int = 4
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--policy", default="GEMS", choices=list(ALL_POLICIES))
     ap.add_argument("--duration", type=float, default=15.0)

@@ -8,7 +8,6 @@ decode against a (possibly ring-buffered) KV cache.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Optional
 
 import jax
@@ -17,14 +16,6 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.launch.sharding import resolves, shard
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep → check_vma
-_SHARD_MAP_CHECK_KW = ("check_vma" if "check_vma"
-                       in inspect.signature(_shard_map).parameters
-                       else "check_rep")
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -361,11 +352,11 @@ def decode_update_attend_sharded(cfg: ArchConfig, q, k_new, v_new, ck, cv,
         out = (o / jnp.maximum(l, 1e-30)[..., None]).astype(q_l.dtype)
         return out.reshape(bl, 1, h, hd), ck_l, cv_l
 
-    out, ck, cv = _shard_map(
+    out, ck, cv = jax.shard_map(
         body, mesh=mesh,
         in_specs=(qs, kvnew, kvnew, cache_spec, cache_spec),
         out_specs=(qs, cache_spec, cache_spec),
-        **{_SHARD_MAP_CHECK_KW: False},
+        check_vma=False,
     )(q, k_new, v_new, ck, cv)
     return out, ck, cv
 

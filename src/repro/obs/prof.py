@@ -4,8 +4,8 @@ Two concerns, both about the *compiled program*, not the scheduler:
 
 * :func:`profile_trace` — a context manager around
   ``jax.profiler.trace`` that captures a TensorBoard/Perfetto-readable
-  device trace into a log directory (no-op with a warning if the
-  profiler backend is unavailable in this build).
+  device trace into a log directory (a profiler that refuses to start
+  raises).
 * :class:`CompileCounter` / :func:`fleet_compile_stats` — retrace
   accounting.  The fleet tick is policy-*generic*: every policy is
   runtime ``PolicyParams`` data, so one ``(dt, fractions, trace spec,
@@ -19,7 +19,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import warnings
 
 import jax
 
@@ -35,18 +34,11 @@ def profile_trace(logdir: str, *, create_perfetto_trace: bool = False):
 
     View with TensorBoard's profile plugin or (with
     ``create_perfetto_trace=True``) the generated ``.perfetto-trace``
-    file in ``ui.perfetto.dev``.  Degrades to a no-op with a warning
-    when the profiler backend refuses to start (some CPU-only or
-    sandboxed builds).
+    file in ``ui.perfetto.dev``.  A profiler that refuses to start
+    raises: a run that asked for a trace never goes on without one.
     """
-    try:
-        jax.profiler.start_trace(
-            logdir, create_perfetto_trace=create_perfetto_trace)
-    except BaseException as e:  # backend may raise non-Exception errors
-        warnings.warn(f"jax.profiler unavailable ({e!r}); "
-                      "profile_trace is a no-op", RuntimeWarning)
-        yield False
-        return
+    jax.profiler.start_trace(
+        logdir, create_perfetto_trace=create_perfetto_trace)
     try:
         yield True
     finally:
@@ -60,8 +52,9 @@ class CompileCounter:
     ...     run_fleet(...)
     >>> cc.count, cc.total_secs
 
-    Uses :mod:`jax.monitoring` duration events, so it observes real
-    backend compiles only — cache hits (jit or persistent) don't count.
+    Uses :mod:`jax.monitoring` duration events: an in-memory jit cache
+    hit doesn't count; a persistent-cache hit counts, with the time it
+    took to read the executable back.
     """
 
     def __init__(self) -> None:
@@ -78,15 +71,7 @@ class CompileCounter:
         return self
 
     def __exit__(self, *exc) -> None:
-        # public unregister didn't exist yet in this jax; fall back to
-        # leaving the (cheap, inert) listener registered if the private
-        # helper moves
-        try:
-            from jax._src.monitoring import \
-                _unregister_event_duration_listener_by_callback
-            _unregister_event_duration_listener_by_callback(self._listen)
-        except (ImportError, ValueError):  # pragma: no cover
-            pass
+        jax.monitoring.unregister_event_duration_listener(self._listen)
 
 
 @dataclasses.dataclass
@@ -123,12 +108,7 @@ def fleet_compile_stats() -> FleetCompileStats:
     """
     from repro.sim import fleet_jax
 
-    sizes = []
-    for prog in fleet_jax._PROGRAM_REGISTRY:
-        try:
-            sizes.append(prog._cache_size())
-        except Exception:  # pragma: no cover - older jax
-            sizes.append(1)
+    sizes = [prog._cache_size() for prog in fleet_jax._PROGRAM_REGISTRY]
     return FleetCompileStats(
         programs=len(sizes), traces=sum(sizes),
         max_traces_per_program=max(sizes, default=0),

@@ -268,6 +268,7 @@ def run_registry_sweep(scenarios=None, policies=("DEMS",), seeds=(0,), *,
     the padded planner the model axis stays padded to the batch maximum,
     padded models simply never count).
     """
+    from repro.launch.mesh import make_mesh
     from repro.scenarios.compile import (compile_registry_batch,
                                          compile_registry_groups)
     from repro.sim.fleet_jax import FleetResult, run_batch
@@ -306,7 +307,7 @@ def run_registry_sweep(scenarios=None, policies=("DEMS",), seeds=(0,), *,
     def auto_mesh(batch):
         r = int(batch.signals.arrive.shape[0])
         n = max(d for d in range(1, jax.device_count() + 1) if r % d == 0)
-        return jax.make_mesh((n,), ("replica",)) if n > 1 else None
+        return make_mesh((n,), ("replica",)) if n > 1 else None
 
     if planner == "bucketed":
         by_key = {}

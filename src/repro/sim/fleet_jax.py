@@ -78,6 +78,7 @@ percentiles, conservation ledger, Perfetto export) lives in
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 from typing import NamedTuple, Optional
@@ -1155,6 +1156,15 @@ def _resolve_policy(policy) -> FleetPolicy:
 # mesh sharding
 # ---------------------------------------------------------------------------
 
+def _on_mesh(mesh: Optional[jax.sharding.Mesh]):
+    """The context a sharded program traces and runs under: with
+    ``jax.set_mesh`` the selection sites see a multi-device mesh and
+    take the partitionable reference over the Mosaic kernel
+    (:func:`repro.kernels.sched_ops.masked_argext`)."""
+    return jax.set_mesh(mesh) if mesh is not None \
+        else contextlib.nullcontext()
+
+
 def _put(a: jax.Array, mesh: jax.sharding.Mesh, names: tuple) -> jax.Array:
     """Place ``a`` with the given per-axis mesh-axis names (None = rep.);
     axes whose size does not divide the mesh axis stay replicated."""
@@ -1388,6 +1398,12 @@ class FleetProgram:
                               self.coop_rounds, self.trace, self.batched,
                               self.hetero, self.donate)
 
+    def lower(self, prof, pp, state, signals) -> jax.stages.Lowered:
+        """The chunk executable lowered for these arguments (arrays, or
+        ``ShapeDtypeStruct`` s placed on described devices); ``.compile()``
+        it for ``memory_analysis()`` or to check a compile for a chip."""
+        return self._jitted.lower(prof, pp, state, tuple(signals))
+
     def step_chunk(self, prof: Profiles, pp: PolicyParams, state: EdgeState,
                    signals: FleetSignals):
         """Advance ``state`` over one signal window.
@@ -1502,7 +1518,8 @@ def run_fleet(models: list[ModelProfile], policy, signals: FleetSignals, *,
     state = prog.init(prof, pol, n_edges, cloud_slots)
     if mesh is not None:
         state = _shard_leading(state, mesh)
-    return prog.run(prof, pol.params(), state, signals, chunk_ticks)
+    with _on_mesh(mesh):
+        return prog.run(prof, pol.params(), state, signals, chunk_ticks)
 
 
 def stack_signals(signals: list[FleetSignals]) -> FleetSignals:
@@ -1619,7 +1636,8 @@ def run_fleet_batch(models: list[ModelProfile], policy,
             state = jax.tree.map(
                 lambda a: _put(a, mesh, (mesh.axis_names[1],)), state)
         signals = _shard_signals(signals, mesh)
-    return prog.run(prof, pol.params(), state, signals)
+    with _on_mesh(mesh):
+        return prog.run(prof, pol.params(), state, signals)
 
 
 class FleetBatch(NamedTuple):
@@ -1752,7 +1770,8 @@ def run_batch(batch: FleetBatch, *, dt: float = 25.0,
         pp = _shard_leading(pp, mesh, axes=1)
         state = _shard_leading(state, mesh, axes=2)
         sig = _shard_signals(sig, mesh)
-    return prog.run(prof, pp, state, sig, chunk_ticks)
+    with _on_mesh(mesh):
+        return prog.run(prof, pp, state, sig, chunk_ticks)
 
 
 def simulate_fleet(models: list[ModelProfile], policy: str, *,

@@ -78,3 +78,37 @@ def test_cache_clear_resets_registry_and_evictions():
     assert stats.programs == 0
     assert stats.traces == 0
     assert stats.evictions == 0
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/jax-cache"])
+def test_enable_compile_cache_places_the_persistent_cache(monkeypatch,
+                                                         env_dir):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and is left to JAX; otherwise
+    the cache is the checkout's fixed ``.jax_cache/``.  Either way every
+    program is cached, however fast it compiled."""
+    import jax
+
+    from repro.launch import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    was = {k: getattr(jax.config, k) for k in keys}
+    try:
+        where = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            assert where == str(compile_cache.CACHE_DIR)
+            assert compile_cache.CACHE_DIR.parent.joinpath(
+                "pyproject.toml").exists()
+            assert jax.config.jax_compilation_cache_dir == where
+        else:
+            assert where == env_dir
+            assert jax.config.jax_compilation_cache_dir \
+                == was["jax_compilation_cache_dir"]
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)

@@ -232,7 +232,8 @@ def test_fleet_task_conservation():
 
 
 def test_fleet_sharded_over_mesh_axis():
-    mesh = jax.make_mesh((jax.device_count(),), ("fleet",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((jax.device_count(),), ("fleet",))
     final = simulate_fleet(MODELS, "DEMS", n_edges=4,
                            duration_ms=30_000.0, mesh=mesh)
     assert np.asarray(final.n_success).sum() > 0

@@ -164,13 +164,14 @@ def test_build_fleet_batch_rejects_mixed_adapt_windows():
 
 _SHARD_SCRIPT = textwrap.dedent("""
     import jax, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.scenarios import compile_registry_batch
     from repro.sim.fleet_jax import run_batch
     batch, rows = compile_registry_batch(
         ("baseline", "rush-hour"), ("DEMS", "DEMS-COOP"), (0, 1),
         duration_ms=8_000.0)
     ref = run_batch(batch)
-    mesh = jax.make_mesh((2, 2), ("replica", "edge"))
+    mesh = make_mesh((2, 2), ("replica", "edge"))
     got = run_batch(batch, mesh=mesh)
     for a, b in zip(jax.tree.leaves(ref), jax.tree.leaves(got)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -179,7 +180,10 @@ _SHARD_SCRIPT = textwrap.dedent("""
 
 
 def test_2d_sharded_run_batch_bitwise_matches_unsharded():
-    env = dict(os.environ,
+    """The child runs on the CPU only (``JAX_PLATFORMS=cpu``, four forced
+    host devices): a test process may hold an accelerator, and a child
+    that reached for it would fail or hang."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4 "
                + os.environ.get("XLA_FLAGS", ""),
                PYTHONPATH="src" + os.pathsep + os.environ.get(
