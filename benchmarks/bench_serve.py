@@ -92,7 +92,9 @@ def bench_controller(*, policy: str = "DEMS-A", n_edges: int = 4,
         compile_s=round(compile_s, 3),
         per_tick_ms=_pcts(steps / window_ticks),
         step_ms=_pcts(steps),
-        ingest_to_decision_ms=_pcts(ctl.ingest_lags_ms),
+        ingest_to_decision_ms={
+            k: None if v is None else round(v, 4)
+            for k, v in snap["ingest_to_decision_ms"].items()},
         completion_rate=round(snap["completion_rate"], 4))
 
 

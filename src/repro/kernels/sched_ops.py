@@ -86,6 +86,7 @@ def _pallas_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
         out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.int32),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
         interpret=interpret,
+        name="_argext_kernel",
     )(s, m)
     return idx[:b, 0], val[:b, 0]
 
@@ -111,19 +112,23 @@ def masked_argext(scores: jax.Array, mask: jax.Array, *, is_max: bool,
     programs traced under a multi-device mesh.
     ``interpret=True`` runs the kernel body through the Pallas
     interpreter on any platform — the kernel-vs-reference test path.
+    Every lowering sits in the ``masked_argext`` name scope, so a profile
+    attributes the selection's operations to it.
     """
     kernel = functools.partial(_kernel_argext, is_max=is_max,
                                block_b=block_b, interpret=bool(interpret))
-    if interpret is not None:
-        return kernel(scores, mask)
-    if jax.sharding.get_abstract_mesh().size > 1:
-        # traced under a multi-device mesh (``jax.set_mesh``): XLA cannot
-        # partition a Mosaic call, so select with the reference, which it
-        # partitions like the rest of the tick
-        return ref.ref_masked_argext(scores, mask, is_max=is_max)
-    return jax.lax.platform_dependent(
-        scores, mask, tpu=kernel,
-        default=functools.partial(ref.ref_masked_argext, is_max=is_max))
+    with jax.named_scope("masked_argext"):
+        if interpret is not None:
+            return kernel(scores, mask)
+        if jax.sharding.get_abstract_mesh().size > 1:
+            # traced under a multi-device mesh (``jax.set_mesh``): XLA
+            # cannot partition a Mosaic call, so select with the
+            # reference, which it partitions like the rest of the tick
+            return ref.ref_masked_argext(scores, mask, is_max=is_max)
+        return jax.lax.platform_dependent(
+            scores, mask, tpu=kernel,
+            default=functools.partial(ref.ref_masked_argext,
+                                      is_max=is_max))
 
 
 def masked_argmax(scores, mask, **kw):

@@ -39,6 +39,7 @@ from typing import Callable, Optional, Sequence, Union
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.task import ModelProfile
 from repro.obs.trace import TraceSpec
@@ -243,8 +244,9 @@ class FleetController:
         they may still receive telemetry.
         """
         out: list[dict] = []
-        while self.tick + self.window_ticks <= int(now_ms / self.dt):
-            out.extend(self._advance(self.window_ticks))
+        with TraceAnnotation("fleet.poll", window=self.windows_run):
+            while self.tick + self.window_ticks <= int(now_ms / self.dt):
+                out.extend(self._advance(self.window_ticks))
         return out
 
     def close(self) -> list[dict]:
@@ -266,17 +268,25 @@ class FleetController:
         return self._run_window(window)
 
     def _advance(self, n_ticks: int) -> list[dict]:
-        return self._run_window(self.builder.emit_window(n_ticks))
+        with TraceAnnotation("fleet.emit", window=self.windows_run):
+            window = self.builder.emit_window(n_ticks)
+        return self._run_window(window)
 
     def _run_window(self, window: FleetSignals) -> list[dict]:
+        # host spans on the profiler's clock, keyed by the window index;
+        # nothing is recorded unless a profiler session is active
+        w = self.windows_run
         tick0 = self.tick - int(np.shape(window.times)[0])
         t0 = time.monotonic()
-        self.state, res = self.prog.step_chunk(
-            self._prof, self._pp, self.state, window)
-        jax.block_until_ready(self.state)
+        with TraceAnnotation("fleet.dispatch", window=w):
+            self.state, res = self.prog.step_chunk(
+                self._prof, self._pp, self.state, window)
+        with TraceAnnotation("fleet.wait", window=w):
+            jax.block_until_ready(self.state)
         wall = time.monotonic()
         self._step_ms.append((wall - t0) * 1e3)
-        records = self._record(tick0, res)
+        with TraceAnnotation("fleet.record", window=w):
+            records = self._record(tick0, res)
         for tk in list(self._submit_walltime):
             if tk < self.tick:
                 self._ingest_lag_ms.append(
@@ -326,11 +336,6 @@ class FleetController:
     def step_latencies_ms(self) -> list[float]:
         """Wall-clock per-window step latencies (recent, bounded)."""
         return list(self._step_ms)
-
-    @property
-    def ingest_lags_ms(self) -> list[float]:
-        """Wall-clock first-submit→decision lags per stepped tick."""
-        return list(self._ingest_lag_ms)
 
     def summary(self) -> dict:
         """Mission-so-far scalar metrics (the replay ``fleet_summary``)."""

@@ -86,6 +86,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import jax_sched as js
 from repro.core import schedulers as _sched
@@ -970,8 +971,10 @@ def make_step(dt: float, edge_frac: float, cloud_frac: float,
         st0 = st
         tr = zero_counters(prof.t_edge.shape[0], tspec) \
             if tspec.counters else None
-        st, tr = _resolve_cloud(st, tr, tspec, prof, pp, now, theta, bw_pen,
-                                cloud_frac, cloud_up, link_up, jit_c)
+        with jax.named_scope("resolve_cloud"):
+            st, tr = _resolve_cloud(st, tr, tspec, prof, pp, now, theta,
+                                    bw_pen, cloud_frac, cloud_up, link_up,
+                                    jit_c)
 
         # §3.3: tasks of a segment are inserted in randomized order; the
         # loop is load-bearing — each insertion's feasibility depends on
@@ -982,12 +985,15 @@ def make_step(dt: float, edge_frac: float, cloud_frac: float,
             mdl = order[i]
             return _route_arrival(s, t, prof, pp, now, mdl, arrive[mdl],
                                   load_mult, edge_up)
-        st, tr = jax.lax.fori_loop(0, prof.t_edge.shape[0], route_one,
-                                   (st, tr))
-        st, tr = _edge_execute(st, tr, tspec, prof, pp, now, dt, edge_frac,
-                               min_edge_t, jit_e, edge_up)
-        st, tr = _gems_act(st, tr, tspec, prof, pp, now, theta, bw_pen,
-                           cloud_frac, link_up, jit_c)
+        with jax.named_scope("route_arrivals"):
+            st, tr = jax.lax.fori_loop(0, prof.t_edge.shape[0], route_one,
+                                       (st, tr))
+        with jax.named_scope("edge_execute"):
+            st, tr = _edge_execute(st, tr, tspec, prof, pp, now, dt,
+                                   edge_frac, min_edge_t, jit_e, edge_up)
+        with jax.named_scope("gems_act"):
+            st, tr = _gems_act(st, tr, tspec, prof, pp, now, theta, bw_pen,
+                               cloud_frac, link_up, jit_c)
         # padded (tick, edge) cells are exact no-ops
         st = jax.tree.map(lambda a, b: jnp.where(valid, a, b), st, st0)
         if tr is not None:
@@ -1275,11 +1281,12 @@ def _fleet_program(dt: float, edge_frac: float, cloud_frac: float,
             if coop_rounds:
                 pre_out, pre_in = state.n_peer_out, state.n_peer_in
                 # crashed edges neither export nor import peer work
-                state = peer_offload(
-                    state, now + dt, pp.coop_slack_ms, coop_rounds,
-                    enable=pp.cooperation,
-                    transfer_cap=pp.coop_transfer_cap,
-                    edge_valid=valid & edge_up)
+                with jax.named_scope("peer_offload"):
+                    state = peer_offload(
+                        state, now + dt, pp.coop_slack_ms, coop_rounds,
+                        enable=pp.cooperation,
+                        transfer_cap=pp.coop_transfer_cap,
+                        edge_valid=valid & edge_up)
                 if tick is not None:
                     # the exchange runs on the stacked fleet state between
                     # ticks; fold its per-edge deltas into the tick row
@@ -1442,9 +1449,11 @@ class FleetProgram:
             # the executable consumes its state input; replay callers
             # (e.g. a FleetBatch swept under several planners) keep
             # their initial state, so donate a copy instead
-            state = jax.tree.map(jnp.copy, state)
+            with TraceAnnotation("fleet.copy_state"):
+                state = jax.tree.map(jnp.copy, state)
         if chunk_ticks is None or chunk_ticks >= n_ticks:
-            state, res = self.step_chunk(prof, pp, state, signals)
+            with TraceAnnotation("fleet.chunk", chunk=0):
+                state, res = self.step_chunk(prof, pp, state, signals)
             return res if self.trace.enabled else state
         bounds = [(lo, min(lo + chunk_ticks, n_ticks))
                   for lo in range(0, n_ticks, chunk_ticks)]
@@ -1454,7 +1463,8 @@ class FleetProgram:
             nxt = slice_signals(signals, *bounds[i + 1],
                                 tick_axis=tick_axis) \
                 if i + 1 < len(bounds) else None
-            state, res = self.step_chunk(prof, pp, state, win)
+            with TraceAnnotation("fleet.chunk", chunk=i):
+                state, res = self.step_chunk(prof, pp, state, win)
             win = nxt
             chunks.append(res)
             if self.donate and (i & 7) == 7:

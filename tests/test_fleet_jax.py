@@ -1,4 +1,7 @@
 """Fleet simulator vs the discrete-event oracle, plus SPMD scaling checks."""
+import functools
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -237,3 +240,29 @@ def test_fleet_sharded_over_mesh_axis():
     final = simulate_fleet(MODELS, "DEMS", n_edges=4,
                            duration_ms=30_000.0, mesh=mesh)
     assert np.asarray(final.n_success).sum() > 0
+
+
+@functools.lru_cache(maxsize=None)
+def _tick_op_names(policy: str) -> tuple:
+    """The ``op_name`` metadata of the 8-edge tick program, lowered."""
+    from repro.sim.fleet_jax import FleetProgram, default_signals
+
+    pol = FleetPolicy.from_name(policy)
+    prog = FleetProgram.for_policy(pol)
+    prof = Profiles.build(MODELS)
+    sig = default_signals(len(MODELS), n_edges=8, duration_ms=200.0)
+    hlo = prog.lower(prof, pol.params(), prog.init(prof, pol, 8),
+                     sig).as_text(dialect="hlo", debug_info=True)
+    return tuple(re.findall(r'op_name="([^"]+)"', hlo))
+
+
+@pytest.mark.parametrize("policy,scope", [
+    ("DEMS-A", "resolve_cloud"), ("DEMS-A", "route_arrivals"),
+    ("DEMS-A", "edge_execute"), ("DEMS-A", "gems_act"),
+    ("DEMS-A", "masked_argext"), ("DEMS-A-COOP", "peer_offload")])
+def test_tick_program_names_its_phases(policy, scope):
+    """Each tick phase runs under its ``jax.named_scope``, so a profile
+    attributes the phase's operations to it (JAX wraps a scope traced
+    under ``vmap`` as ``vmap(<scope>)``)."""
+    ops = _tick_op_names(policy)
+    assert any(scope in re.split(r"[/()]", o) for o in ops)

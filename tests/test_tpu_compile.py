@@ -15,6 +15,8 @@ one process may load the TPU library, and the test workers all import
 this file.  The persistent compilation cache is off around the compiles
 (an entry written for a described chip cannot be read back here).
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -27,6 +29,7 @@ from repro.sim.fleet_jax import (EDGE_CAP, FleetPolicy, FleetProgram,
                                  Profiles, default_signals)
 
 MODELS = [TABLE1[n] for n in PASSIVE]
+PHASES = ("resolve_cloud", "route_arrivals", "edge_execute", "gems_act")
 
 
 @pytest.fixture(scope="module")
@@ -59,6 +62,8 @@ def _assert_kernel(compiled, n_sites: int = 1) -> None:
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= n_sites, \
         "selection did not lower to the Mosaic kernel"
+    # the kernel keeps its name and scope, which a profile reports
+    assert "%_argext_kernel" in hlo and "masked_argext" in hlo
 
 
 @pytest.mark.parametrize("rows,n", [(3, 64), (8, 64), (16, EDGE_CAP),
@@ -96,6 +101,11 @@ def test_tick_program_compiles_with_kernel(one_chip, policy, n_edges, sites):
         _on(one_chip, sig)).compile()
     _assert_kernel(compiled, sites)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    # the compiled program keeps each tick phase's name scope, which a
+    # profile reports as each operation's op_name
+    ops = re.findall(r'op_name="([^"]+)"', compiled.as_text())
+    for scope in PHASES + (("peer_offload",) if "COOP" in policy else ()):
+        assert any(scope in re.split(r"[/()]", o) for o in ops), scope
 
 
 def test_batched_sweep_program_compiles_with_kernel(one_chip):
