@@ -320,6 +320,46 @@ def gems_winnable(lam, lam_hat, prev_lam, alpha, now, win_end,
 
 
 # ---------------------------------------------------------------------------
+# one-hot selects and segment reductions
+# ---------------------------------------------------------------------------
+# Under the fleet ``vmap`` an indexed update or per-edge lookup lowers to a
+# batched scatter or gather, which the TPU applies one index at a time;
+# a compare-select over a one-hot match runs in parallel instead.
+
+def onehot_max(match: jax.Array, vals: jax.Array) -> jax.Array:
+    """Per row ``i``, the largest ``vals[j]`` with ``match[i, j]``; the
+    dtype's lowest value (False for bool) where none matches.
+
+    With at most one True a row this is the lookup ``vals[j]``, bit for
+    bit: a max passes -0.0 through, where a masked sum would give +0.0.
+    """
+    if vals.dtype == jnp.bool_:
+        low = False
+    elif jnp.issubdtype(vals.dtype, jnp.floating):
+        low = -jnp.inf
+    else:
+        low = jnp.iinfo(vals.dtype).min
+    return jnp.where(match, vals[None, :], low).max(-1)
+
+
+def onehot_segment(data, segment_ids, num_segments: int,
+                   op: str = "sum") -> jax.Array:
+    """``jax.ops.segment_sum`` (``op="sum"``) or ``segment_max``
+    (``op="max"``) as a reduction over an ``[M, K]`` one-hot match.
+
+    The result is the scatter's: ids outside ``[0, num_segments)`` are
+    dropped, an empty segment reads 0 (sum) or the dtype's lowest value
+    (max), and integer sums and every max are exact (a float sum may
+    round in another order).
+    """
+    data = jnp.asarray(data)
+    hit = jnp.arange(num_segments)[:, None] == segment_ids[None, :]
+    if op == "max":
+        return onehot_max(hit, data)
+    return jnp.where(hit, data[None, :], 0).sum(-1)
+
+
+# ---------------------------------------------------------------------------
 # §5.4 — DEMS-A adaptation
 # ---------------------------------------------------------------------------
 
@@ -426,17 +466,15 @@ def adapt_feed_batch(st: AdaptState, model_ids, sent, obs, obs_val, skip,
     """
     m, w = st.buf.shape
     k = model_ids.shape[0]
-    cnt = jax.ops.segment_sum(obs.astype(jnp.int32), model_ids,
-                              num_segments=m)                     # i32[M]
+    cnt = onehot_segment(obs.astype(jnp.int32), model_ids, m)     # i32[M]
     cs = jnp.where(
-        jax.ops.segment_sum(sent.astype(jnp.int32), model_ids,
-                            num_segments=m) > 0,
+        onehot_segment(sent.astype(jnp.int32), model_ids, m) > 0,
         -1.0, st.cooling_start)
     cur, buf, count, idx = st.current, st.buf, st.count, st.idx
     if with_obs:
         jmax = k if max_obs is None else min(k, max_obs)
-        v = jax.ops.segment_max(jnp.where(obs, obs_val, NEG), model_ids,
-                                num_segments=m)                   # f32[M]
+        v = onehot_segment(jnp.where(obs, obs_val, NEG), model_ids, m,
+                           "max")                                 # f32[M]
         j = jnp.arange(jmax)[None, :]                             # [1,J]
         fill = jnp.clip(w - count, 0, None)[:, None]              # [M,1]
         # the j-th observation of model m writes slot: fill positions
@@ -466,8 +504,7 @@ def adapt_feed_batch(st: AdaptState, model_ids, sent, obs, obs_val, skip,
         cur = jax.lax.fori_loop(0, jmax, ratchet, cur)
         count = jnp.minimum(st.count + cnt, w)
         idx = (st.idx + (cnt - jnp.clip(w - st.count, 0, cnt))) % w
-    any_skip = jax.ops.segment_sum(skip.astype(jnp.int32), model_ids,
-                                   num_segments=m) > 0
+    any_skip = onehot_segment(skip.astype(jnp.int32), model_ids, m) > 0
     inflated = cur > static
     expired = (cs >= 0) & (now - cs >= t_cp)
     new_cur = jnp.where(any_skip & inflated & expired, static, cur)
@@ -492,10 +529,11 @@ def edge_push(q: EdgeQueue, key, seq, t_edge, deadline, model,
     """
     abs_dl = deadline if abs_dl is None else abs_dl
     free = ~q.valid
-    slot = jnp.argmax(free)
     ok = free.any() & enable
+    # a one-hot select, not ``arr.at[slot].set``: no scatter under vmap
+    at = (jnp.arange(free.shape[0]) == jnp.argmax(free)) & ok
     def set_at(arr, v):
-        return jnp.where(ok, arr.at[slot].set(v), arr)
+        return jnp.where(at, jnp.asarray(v).astype(arr.dtype), arr)
     return EdgeQueue(
         valid=set_at(q.valid, True), key=set_at(q.key, key),
         seq=set_at(q.seq, seq), t_edge=set_at(q.t_edge, t_edge),

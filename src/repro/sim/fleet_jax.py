@@ -696,11 +696,17 @@ def _offer_cloud_many(st: EdgeState, prof: Profiles, pp: PolicyParams, now,
     """
     if t_cur is None:
         t_cur = _t_cloud_cur(st, prof, pp, now)
-    t_hat = t_cur[models]
+    # ``table[models]`` as a one-hot select over the model axis, not a
+    # per-edge gather
+    is_model = models[:, None] == jnp.arange(t_cur.shape[0])[None, :]
+
+    def lookup(table):
+        return js.onehot_max(is_model, table)
+    t_hat = lookup(t_cur)
     feasible = now + t_hat <= deadlines
     # SJF-E+C (cloud_neg_ok) sends γ^C≤0 tasks to the cloud anyway; every
     # other policy rejects (or, stealing, parks) them
-    negative = (prof.gamma_c[models] <= 0) & ~pp.cloud_neg_ok
+    negative = (lookup(prof.gamma_c) <= 0) & ~pp.cloud_neg_ok
     trig_steal = jnp.where(negative, deadlines - t_edges,
                            jnp.maximum(now, deadlines - t_hat
                                        - pp.cloud_margin))
@@ -713,21 +719,20 @@ def _offer_cloud_many(st: EdgeState, prof: Profiles, pp: PolicyParams, now,
     steal_only = jnp.where(pp.stealing, negative, False)
 
     free = ~st.cq.valid
-    qc = free.shape[0]
     ai = accept.astype(jnp.int32)
     arank = jnp.cumsum(ai) - ai
     pushed = accept & (arank < free.sum())
-    tgt = jnp.where(pushed, arank, qc)
-
-    def by_rank(vals):
-        return jnp.zeros(qc, vals.dtype).at[tgt].set(vals, mode="drop")
-
     fi = free.astype(jnp.int32)
     frank = jnp.cumsum(fi) - fi
     fill = free & (frank < pushed.sum())
+    # the free slot of rank r takes the pushed offer of rank r: one
+    # [Qc, K] match, applied to every field by compare-select (not a
+    # scatter by rank and a gather back)
+    hit = fill[:, None] & pushed[None, :] & (frank[:, None]
+                                             == arank[None, :])
 
     def put(old, vals):
-        return jnp.where(fill, by_rank(vals)[frank], old)
+        return jnp.where(fill, js.onehot_max(hit, vals), old)
 
     st = st._replace(
         cq=js.CloudQueue(
@@ -736,7 +741,7 @@ def _offer_cloud_many(st: EdgeState, prof: Profiles, pp: PolicyParams, now,
             t_edge=put(st.cq.t_edge, t_edges),
             deadline=put(st.cq.deadline, deadlines),
             steal_only=put(st.cq.steal_only, steal_only),
-            rank=put(st.cq.rank, prof.steal_rank[models])),
+            rank=put(st.cq.rank, lookup(prof.steal_rank))),
         cq_model=put(st.cq_model, models),
         cq_blocked=st.cq_blocked & ~fill)
     skip = enable & ~accept & pp.use_cloud & pp.adaptive
@@ -820,8 +825,7 @@ def _route_arrival(st: EdgeState, tr: Optional[TickCounters],
     offer = jnp.concatenate([vic, jnp.asarray(to_cloud)[None]])
     st, pushed, accepted = _offer_cloud_many(st, prof, pp, now, models, dls,
                                              tes, offer, t_cur=t_cur)
-    add = functools.partial(jax.ops.segment_sum,
-                            num_segments=prof.t_edge.shape[0])
+    m = prof.t_edge.shape[0]
     eq = js.edge_remove(st.eq, vic)
     eq, ok = js.edge_push(eq, key, st.seq, te, sched_dl, model,
                           enable=insert_edge, abs_dl=abs_dl)
@@ -836,8 +840,9 @@ def _route_arrival(st: EdgeState, tr: Optional[TickCounters],
         drop_qfull=lost + (offer & accepted & ~pushed).sum())
     return st._replace(
         eq=eq, seq=st.seq + arrive.astype(jnp.int32),
-        n_drop=st.n_drop.at[model].add(lost)
-        + add((offer & ~pushed).astype(jnp.int32), models)), tr
+        n_drop=st.n_drop + jnp.where(jnp.arange(m) == model, lost, 0)
+        + js.onehot_segment((offer & ~pushed).astype(jnp.int32), models,
+                            m)), tr
 
 
 def _edge_execute(st: EdgeState, tr: Optional[TickCounters],

@@ -266,3 +266,230 @@ def test_tick_program_names_its_phases(policy, scope):
     under ``vmap`` as ``vmap(<scope>)``)."""
     ops = _tick_op_names(policy)
     assert any(scope in re.split(r"[/()]", o) for o in ops)
+
+
+# ---------------------------------------------------------------------------
+# arrival routing without scatters
+# ---------------------------------------------------------------------------
+
+def _scatter_segment(data, segment_ids, num_segments, op="sum"):
+    seg = jax.ops.segment_sum if op == "sum" else jax.ops.segment_max
+    return seg(data, segment_ids, num_segments=num_segments)
+
+
+def _offer_cloud_many_scatter(st, prof, pp, now, models, deadlines, t_edges,
+                              enable):
+    """The cloud admission's scatter form: accepted offers are scattered
+    by rank, then gathered back to the free slots in slot order."""
+    import jax.numpy as jnp
+
+    from repro.core import jax_sched as js
+    from repro.sim import fleet_jax as fj
+
+    t_cur = fj._t_cloud_cur(st, prof, pp, now)
+    t_hat = t_cur[models]
+    feasible = now + t_hat <= deadlines
+    negative = (prof.gamma_c[models] <= 0) & ~pp.cloud_neg_ok
+    trig_steal = jnp.where(negative, deadlines - t_edges,
+                           jnp.maximum(now, deadlines - t_hat
+                                       - pp.cloud_margin))
+    accept_steal = enable & feasible & jnp.where(negative,
+                                                 trig_steal >= now, True)
+    accept_plain = enable & feasible & ~negative
+    accept = pp.use_cloud & jnp.where(pp.stealing, accept_steal,
+                                      accept_plain)
+    trigger = jnp.where(pp.stealing, trig_steal, now)
+    steal_only = jnp.where(pp.stealing, negative, False)
+
+    free = ~st.cq.valid
+    qc = free.shape[0]
+    ai = accept.astype(jnp.int32)
+    arank = jnp.cumsum(ai) - ai
+    pushed = accept & (arank < free.sum())
+    tgt = jnp.where(pushed, arank, qc)
+
+    def by_rank(vals):
+        return jnp.zeros(qc, vals.dtype).at[tgt].set(vals, mode="drop")
+
+    fi = free.astype(jnp.int32)
+    frank = jnp.cumsum(fi) - fi
+    fill = free & (frank < pushed.sum())
+
+    def put(old, vals):
+        return jnp.where(fill, by_rank(vals)[frank], old)
+
+    st = st._replace(
+        cq=js.CloudQueue(
+            valid=st.cq.valid | fill,
+            trigger=put(st.cq.trigger, trigger),
+            t_edge=put(st.cq.t_edge, t_edges),
+            deadline=put(st.cq.deadline, deadlines),
+            steal_only=put(st.cq.steal_only, steal_only),
+            rank=put(st.cq.rank, prof.steal_rank[models])),
+        cq_model=put(st.cq_model, models),
+        cq_blocked=st.cq_blocked & ~fill)
+    skip = enable & ~accept & pp.use_cloud & pp.adaptive
+    st = st._replace(adapt=js.adapt_feed_batch(
+        st.adapt, models, jnp.zeros_like(skip), jnp.zeros_like(skip),
+        jnp.zeros_like(t_hat), skip, now, prof.t_cloud, pp.adapt_eps,
+        pp.adapt_cooling_ms, with_obs=False))
+    return st, pushed, accept
+
+
+def _random_offers(seed, policy, taken, offered, n_edges=48):
+    """A fleet of random per-edge states and one batch of cloud offers
+    (33 a edge: a full edge queue of victims and the arrival)."""
+    import jax.numpy as jnp
+
+    from repro.sim import fleet_jax as fj
+
+    rng = np.random.default_rng(seed)
+    m_real, m = 6, 8          # two inert padded models at +inf
+    qc, k = fj.CLOUD_CAP, fj.EDGE_CAP + 1
+
+    def pad(x, val):
+        return np.concatenate([x, np.full(m - m_real, val)]).astype(
+            np.float32)
+
+    t_cloud = rng.uniform(100, 600, m_real)
+    rank = rng.uniform(-1, 1, m_real)
+    rank[0] = -0.0
+    prof = fj.Profiles(
+        t_edge=pad(rng.uniform(50, 300, m_real), np.inf),
+        t_cloud=pad(t_cloud, np.inf),
+        deadline=pad(rng.uniform(300, 1500, m_real), np.inf),
+        gamma_e=pad(rng.uniform(0, 3, m_real), 0.0),
+        gamma_c=pad(rng.uniform(-2, 3, m_real), 0.0),
+        cost_e=pad(rng.uniform(0, 1, m_real), 0.0),
+        cost_c=pad(rng.uniform(0, 1, m_real), 0.0),
+        steal_rank=pad(rank, 0.0),
+        qoe_alpha=pad(np.full(m_real, 0.9), 0.0),
+        qoe_beta=pad(np.ones(m_real), 0.0),
+        qoe_window=pad(np.full(m_real, 60_000.0), np.inf))
+    prof = fj.Profiles(*(jnp.asarray(x) for x in prof))
+    pp = FleetPolicy.from_name(policy).params()
+
+    def f32(*shape, lo=0.0, hi=1.0):
+        return rng.uniform(lo, hi, shape).astype(np.float32)
+
+    now = f32(n_edges, lo=1000, hi=5000)
+    st = jax.tree.map(lambda a: np.broadcast_to(a, (n_edges,) + a.shape),
+                      fj.init_state(prof))
+    cur = (np.asarray(prof.t_cloud)[None, :] * f32(n_edges, m, lo=0.8,
+                                                   hi=1.5))
+    st = st._replace(
+        cq=st.cq._replace(
+            valid=rng.random((n_edges, qc)) < taken,
+            trigger=f32(n_edges, qc, hi=5000), t_edge=f32(n_edges, qc,
+                                                          hi=400),
+            deadline=f32(n_edges, qc, hi=8000),
+            steal_only=rng.random((n_edges, qc)) < 0.3,
+            rank=f32(n_edges, qc, lo=-1)),
+        cq_model=rng.integers(0, m, (n_edges, qc), dtype=np.int32),
+        cq_blocked=rng.random((n_edges, qc)) < 0.3,
+        cloud_busy_until=now[:, None] + f32(n_edges,
+                                            fj.CLOUD_SLOTS, lo=-300, hi=600),
+        adapt=st.adapt._replace(
+            current=np.where(np.isfinite(cur), cur, np.inf).astype(
+                np.float32),
+            cooling_start=np.where(rng.random((n_edges, m)) < 0.5, -1.0,
+                                   now[:, None] - f32(n_edges, m,
+                                                      hi=20_000)).astype(
+                np.float32)))
+    st = jax.tree.map(jnp.asarray, st)
+    t_edges = f32(n_edges, k, lo=50, hi=400)
+    t_edges[:, 3] = -0.0
+    offers = dict(
+        models=jnp.asarray(rng.integers(0, m, (n_edges, k), dtype=np.int32)),
+        deadlines=jnp.asarray(now[:, None] + f32(n_edges, k, lo=-100,
+                                                 hi=3000)),
+        t_edges=jnp.asarray(t_edges),
+        enable=jnp.asarray(rng.random((n_edges, k)) < offered))
+    return prof, pp, st, jnp.asarray(now), offers
+
+
+_OFFER_CASES = {
+    # name: (policy, share of cloud-queue slots taken, share offered, what
+    # the batch must show for the case to test what it names)
+    "empty_queue": ("DEMS-A", 0.0, 0.5, lambda p, a: p.any()),
+    "full_queue": ("DEMS-A", 1.0, 0.5, lambda p, a: a.any() & ~p.any()),
+    "no_offers": ("DEMS-A", 0.5, 0.0, lambda p, a: ~a.any()),
+    "all_offered": ("DEMS-A", 0.3, 1.0, lambda p, a: p.sum() > 0),
+    "capacity_drops": ("DEMS-A", 0.8, 1.0, lambda p, a: (a & ~p).any()),
+    "sjf_cloud_neg_ok": ("SJF-E+C", 0.5, 0.7, lambda p, a: p.any()),
+    "stealing": ("DEMS", 0.5, 0.7, lambda p, a: p.any()),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_OFFER_CASES))
+def test_offer_cloud_many_matches_scatter_form(case, monkeypatch):
+    """The one-hot compaction admits to the same cloud-queue slots, with
+    the same fields, bit for bit, as the scatter-and-gather form, and the
+    estimator's skip feed matches its segment-reduction form."""
+    from repro.core import jax_sched as js
+    from repro.sim.fleet_jax import _offer_cloud_many
+
+    policy, taken, offered, shows = _OFFER_CASES[case]
+    prof, pp, st, now, o = _random_offers(
+        sorted(_OFFER_CASES).index(case), policy, taken, offered)
+    args = (st, now, o["models"], o["deadlines"], o["t_edges"], o["enable"])
+
+    def run(fn):
+        return jax.vmap(lambda s, n, *a: fn(s, prof, pp, n, *a))(*args)
+
+    got = run(_offer_cloud_many)
+    with monkeypatch.context() as mp:
+        mp.setattr(js, "onehot_segment", _scatter_segment)
+        want = run(_offer_cloud_many_scatter)
+    assert bool(shows(np.asarray(want[1]), np.asarray(want[2])))
+    leaves_got, tree = jax.tree.flatten(got)
+    leaves_want, tree_want = jax.tree.flatten(want)
+    assert tree == tree_want
+    for path, g, w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
+                          leaves_got, leaves_want):
+        g, w = np.asarray(g), np.asarray(w)
+        assert g.dtype == w.dtype and g.shape == w.shape, path[0]
+        assert g.tobytes() == w.tobytes(), jax.tree_util.keystr(path[0])
+
+
+def _primitive_names(jaxpr) -> set:
+    """Every primitive of a jaxpr and of the jaxprs nested in it."""
+    from jax.extend import core as jcore
+
+    out = set()
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(x, jcore.ClosedJaxpr):
+                    out |= _primitive_names(x.jaxpr)
+                elif isinstance(x, jcore.Jaxpr):
+                    out |= _primitive_names(x)
+    return out
+
+
+def test_route_arrival_has_no_scatter():
+    """Under the fleet ``vmap`` a scatter runs one index at a time on the
+    TPU: the per-arrival path places, counts and feeds the estimator by
+    one-hot compare-select, and applies no match with a matmul (a float
+    dot on the TPU rounds through bf16 passes)."""
+    import jax.numpy as jnp
+
+    from repro.sim import fleet_jax as fj
+
+    prof = Profiles.build(MODELS)
+    pp = FleetPolicy.from_name("DEMS-A").params()
+    n = 4
+    st = jax.tree.map(lambda a: jnp.broadcast_to(a, (n,) + a.shape),
+                      fj.init_state(prof))
+
+    def route(s, model, arrive, load_mult):
+        return fj._route_arrival(s, None, prof, pp, jnp.float32(100.0),
+                                 model, arrive, load_mult)[0]
+
+    prims = _primitive_names(jax.make_jaxpr(jax.vmap(route))(
+        st, jnp.zeros(n, jnp.int32), jnp.ones(n, bool),
+        jnp.ones(n, jnp.float32)).jaxpr)
+    assert "gather" in prims           # the walk does reach the route's ops
+    assert not {p for p in prims if p.startswith("scatter")}, prims
+    assert "dot_general" not in prims

@@ -258,3 +258,92 @@ def test_queue_capacity_overflow_reports_failure():
         assert bool(ok)
     q, ok = js.edge_push(q, 9.0, 9, 1.0, 1.0, 0)
     assert not bool(ok)
+
+
+# ---------------------------------------------------------------------------
+# one-hot forms of the per-arrival scatters
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("op", ["sum", "max"])
+@pytest.mark.parametrize("dtype", ["int32", "float32"])
+@pytest.mark.parametrize("mask", ["random", "all_false"])
+def test_onehot_segment_matches_segment_ops(op, dtype, mask):
+    """``onehot_segment`` is ``jax.ops.segment_sum``/``segment_max`` bit
+    for bit, batched as in the fleet ``vmap``: out-of-range ids dropped,
+    empty segments at the scatter's identity, padded models' +inf
+    values kept."""
+    import jax
+
+    rng = np.random.default_rng(7)
+    n, k, m_real, m = 16, 33, 6, 8
+    ids = rng.integers(-1, m + 1, (n, k)).astype(np.int32)
+    vals = rng.integers(-50, 50, (n, k)).astype(dtype)
+    if dtype == "float32":
+        vals[ids >= m_real] = np.inf          # padded models sit at +inf
+    on = (rng.random((n, k)) < 0.5) if mask == "random" else np.zeros(
+        (n, k), bool)
+    # what a masked-off event contributes (the program's NEG for a max)
+    if op == "sum":
+        idle = 0
+    elif dtype == "float32":
+        idle = js.NEG
+    else:
+        idle = np.iinfo(np.int32).min + 1
+    data = jnp.asarray(np.where(on, vals, idle).astype(dtype))
+    seg = jax.ops.segment_sum if op == "sum" else jax.ops.segment_max
+    want = jax.vmap(lambda d, i: seg(d, i, num_segments=m))(data, ids)
+    got = jax.vmap(lambda d, i: js.onehot_segment(d, i, m, op))(data, ids)
+    assert got.dtype == want.dtype
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+
+
+def _edge_push_scatter(q, key, seq, t_edge, deadline, model, enable=True,
+                       abs_dl=None):
+    """``edge_push`` as a scalar-slot scatter, ``arr.at[slot].set``."""
+    abs_dl = deadline if abs_dl is None else abs_dl
+    free = ~q.valid
+    slot = jnp.argmax(free)
+    ok = free.any() & enable
+
+    def set_at(arr, v):
+        return jnp.where(ok, arr.at[slot].set(v), arr)
+    return js.EdgeQueue(
+        valid=set_at(q.valid, True), key=set_at(q.key, key),
+        seq=set_at(q.seq, seq), t_edge=set_at(q.t_edge, t_edge),
+        deadline=set_at(q.deadline, deadline),
+        abs_dl=set_at(q.abs_dl, abs_dl), model=set_at(q.model, model),
+    ), ok
+
+
+@pytest.mark.parametrize("fill", ["empty", "partial", "full"])
+@pytest.mark.parametrize("enable", [True, False])
+def test_edge_push_one_hot_matches_scatter(fill, enable):
+    """The one-hot ``edge_push`` writes the same slot and fields as the
+    scatter form, on empty, partial and full queues, enabled or not."""
+    import jax
+
+    rng = np.random.default_rng(11)
+    n = 8
+    share = dict(empty=0.0, partial=0.5, full=1.0)[fill]
+    q = js.EdgeQueue(
+        valid=jnp.asarray(rng.random((n, CAP)) < share),
+        key=jnp.asarray(rng.uniform(0, 900, (n, CAP)), jnp.float32),
+        seq=jnp.asarray(rng.integers(0, 99, (n, CAP)), jnp.int32),
+        t_edge=jnp.asarray(rng.uniform(0, 300, (n, CAP)), jnp.float32),
+        deadline=jnp.asarray(rng.uniform(0, 900, (n, CAP)), jnp.float32),
+        abs_dl=jnp.asarray(rng.uniform(0, 900, (n, CAP)), jnp.float32),
+        model=jnp.asarray(rng.integers(0, M, (n, CAP)), jnp.int32))
+    new = (jnp.asarray(rng.uniform(0, 900, n), jnp.float32),
+           jnp.asarray(rng.integers(0, 99, n), jnp.int32),
+           jnp.asarray(rng.uniform(0, 300, n), jnp.float32),
+           jnp.asarray(rng.uniform(0, 900, n), jnp.float32),
+           jnp.asarray(rng.integers(0, M, n), jnp.int32),
+           jnp.full(n, enable),
+           jnp.asarray(rng.uniform(0, 900, n), jnp.float32))
+    got = jax.vmap(js.edge_push)(q, *new)
+    want = jax.vmap(_edge_push_scatter)(q, *new)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+    if fill == "full" or not enable:
+        assert not np.asarray(got[1]).any()
