@@ -4,12 +4,15 @@ program's place, must come out not correct.
 
     python3 bench/control.py --workload metro1024-steady-demsa \\
         --seeds 11 12 13 --ticks 640
+    python3 bench/control.py --workload registry-sweep-warm \\
+        --seeds 11 12 13 --ticks 12000
 
 For each seed it builds the cell's traffic as a run does (on the device,
 at the cell's size), steps the float32 reference and the bfloat16
 control over the first ``--ticks`` ticks, and prints, per seed, every
 number ``correct`` compares, read from the control against the
-reference, beside the configuration's limit.  The configuration states
+reference, beside the configuration's limit (a sweep's ``--ticks`` is the
+length of each mission: 12000 is the whole 300 s).  The configuration states
 float32 milliseconds, so bfloat16 is the precision below it.  The
 benchmark's own runs never run this; it sets the upper readings the
 limits are chosen under (PERF.md).
@@ -79,6 +82,30 @@ def live_readings(cfg, traffic, seed: int, ticks: int) -> dict:
                                                               want_rec))
 
 
+def sweep_readings(cfg, traffic, seed: int, ticks: int) -> dict:
+    """The sweep's buckets lowered as a run lowers them, missions of
+    ``ticks`` ticks; the bfloat16 reference against the float32 one."""
+    sys.path.insert(0, str(BENCH.parent / "src"))
+    from repro.scenarios.compile import compile_registry_groups
+
+    from harness import sweep
+
+    dt = cfg["scheduler"]["dt_ms"]
+    scenarios, policies, seeds, _ = sweep.plan(traffic, seed)
+    buckets = [(jax.device_get(batch.signals._asdict()), rows)
+               for batch, rows in compile_registry_groups(
+                   scenarios, policies, seeds, dt=dt,
+                   duration_ms=ticks * dt)]
+    want = sweep.reference(cfg, buckets)
+    got = sweep.reference(cfg, buckets, "bfloat16")
+    gap, diff, arrived = 0, 0, 0
+    for (_, _, w), (_, _, g) in zip(want, got):
+        gap = max(gap, check.ledger_gap(g["outcome"], g["arrived"]))
+        diff += check.mismatch_count(g["outcome"], w["outcome"])
+        arrived += int(w["arrived"].sum())
+    return dict(ledger_gap=gap, mismatch_pct=100.0 * diff / max(arrived, 1))
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -87,7 +114,8 @@ def main(argv=None) -> int:
                     help="ticks to step: as many as a run of the cell steps")
     args = ap.parse_args(argv)
     cell, cfg, traffic = load(args.workload)
-    read = live_readings if traffic["entry"] == "live" else replay_readings
+    read = dict(live=live_readings, replay=replay_readings,
+                sweep=sweep_readings)[traffic["entry"]]
     print(f"device: {jax.devices()[0].device_kind} x{len(jax.devices())}",
           flush=True)
     for seed in args.seeds:

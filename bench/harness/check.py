@@ -33,10 +33,13 @@ _EDGE_AXIS = ("theta", "bw", "arrive", "order", "load_mult", "valid",
               "exec_jit", "edge_up", "link_up")
 
 
-def reference(cfg: dict, policy: str, n_edges: int, dtype=np.float32):
+def reference(cfg: dict, policy: str, n_edges: int, dtype=np.float32,
+              **lanes):
     """The policy's plain reference, from its own file
     ``bench/refs/<policy>.py``: ``make(cfg, n_edges, dtype)`` returns an
-    object with ``step(inputs) -> record`` and ``outcome()``."""
+    object with ``step(inputs) -> record`` and ``outcome()``.  ``lanes``
+    (``slots``, ``groups``: per edge) stack independent runs, and are
+    passed on only where given."""
     path = REFS / f"{policy}.py"
     if not path.is_file():
         raise FileNotFoundError(f"no plain reference for policy {policy!r} "
@@ -45,7 +48,7 @@ def reference(cfg: dict, policy: str, n_edges: int, dtype=np.float32):
         "bench_ref_" + policy.replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.make(cfg, n_edges, dtype)
+    return mod.make(cfg, n_edges, dtype, **lanes)
 
 
 def ref_replay(cfg: dict, policy: str, segments: list[dict],
@@ -107,11 +110,15 @@ def ledger_gap(got: dict, arrived: np.ndarray) -> int:
     return int(np.abs(gap).max(initial=0))
 
 
-def mismatch_pct(got: dict, want: dict, arrived: np.ndarray) -> float:
-    diff = sum(int(np.abs(np.asarray(got[f], np.int64)
+def mismatch_count(got: dict, want: dict) -> int:
+    """Sum of absolute differences over the compared integer fields."""
+    return sum(int(np.abs(np.asarray(got[f], np.int64)
                           - np.asarray(want[f], np.int64)).sum())
                for f in FIELDS)
-    return 100.0 * diff / max(int(arrived.sum()), 1)
+
+
+def mismatch_pct(got: dict, want: dict, arrived: np.ndarray) -> float:
+    return 100.0 * mismatch_count(got, want) / max(int(arrived.sum()), 1)
 
 
 def record_mismatch_pct(got: list[dict], want: list[dict]) -> float:

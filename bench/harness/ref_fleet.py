@@ -18,6 +18,11 @@ system under test.  It keeps the same per-tick order of phases:
 4. between ticks, with cooperation, ``coop_rounds`` peer transfers of the
    worst-slack exportable task to the least-loaded other edge.
 
+Edges may be stacked from independent runs: ``slots`` gives each edge its
+own FaaS slot count, and ``groups`` each edge its run, so the peer
+exchange stays inside a run (the runs are contiguous blocks of edges of
+one size).
+
 Floats are held in ``dtype`` (float32, as the configuration states; the
 control passes bfloat16).  Queue times are whole milliseconds at nominal
 edge speed, so sums over a queue are exact in any order; every
@@ -106,17 +111,23 @@ def _excl_cumsum(mask: np.ndarray) -> np.ndarray:
     return np.cumsum(m, axis=-1, dtype=np.int32) - m
 
 
+def _take(a: np.ndarray, idx: np.ndarray) -> np.ndarray:
+    """``np.take_along_axis(a, idx, -1)`` for 2-D ``a`` and ``idx``."""
+    return a[np.arange(a.shape[0])[:, None], idx]
+
+
 def _first_true(mask: np.ndarray) -> np.ndarray:
     """Index of the first True per row (0 for a row with none)."""
     return np.argmax(mask, axis=-1)
 
 
 def _masked_arg(scores, mask, is_max: bool):
-    """First arg-extremum over enabled entries, per row; -1 where none."""
+    """First arg-extremum over enabled entries, per row of a 2-D array;
+    -1 where none."""
     fill = NEG if is_max else POS
     v = np.where(mask, scores, scores.dtype.type(fill))
     idx = np.argmax(v, -1) if is_max else np.argmin(v, -1)
-    best = np.take_along_axis(v, idx[..., None], -1)[..., 0]
+    best = _take(v, idx[:, None])[:, 0]
     return np.where(mask.any(-1), idx, -1), best
 
 
@@ -125,17 +136,40 @@ _STATE = ("eq_valid", "eq_key", "eq_seq", "eq_te", "eq_dl", "eq_abs",
           "cq_rank", "cq_model", "cq_blocked", "busy_rem", "busy_until",
           "seq", "n_success", "n_miss", "n_drop", "n_stolen", "n_edge_exec",
           "qos", "n_peer_out", "n_peer_in", "a_buf", "a_count", "a_idx",
-          "a_cur", "a_cool")
+          "a_cur", "a_cool", "n_slots")
+
+
+def _group_size(groups, n_edges: int) -> int:
+    """Edges per run for per-edge run ids ``groups`` (``None``: one run of
+    every edge); runs must be contiguous blocks of one size."""
+    if groups is None:
+        return max(n_edges, 1)
+    groups = np.asarray(groups)
+    _, sizes = np.unique(groups, return_counts=True)
+    g = int(sizes[0])
+    if (len(groups) != n_edges or np.any(sizes != g)
+            or np.any(groups != np.repeat(groups[::g], g))):
+        raise ValueError("groups must be contiguous runs of equal size")
+    return g
 
 
 class FleetRef:
-    """Stacked state of ``n_edges`` edges, stepped one tick at a time."""
+    """Stacked state of ``n_edges`` edges, stepped one tick at a time.
+
+    ``slots`` (per edge, default the configuration's ``cloud_slots``) is
+    each edge's FaaS slot count; ``groups`` (per edge) the run each edge
+    belongs to, for the peer exchange (default: one run)."""
 
     def __init__(self, table: Table, p: Params, n_edges: int,
-                 dtype=np.float32):
+                 dtype=np.float32, slots=None, groups=None):
         self.tb, self.p, self.ft = table, p, dtype
         e, q, c, m = n_edges, p.edge_cap, p.cloud_cap, len(table.names)
-        s, w = p.cloud_slots, p.adapt_window
+        w = p.adapt_window
+        n_slots = np.broadcast_to(
+            np.asarray(p.cloud_slots if slots is None else slots, np.int32),
+            (e,)).copy()
+        s = int(n_slots.max(initial=1))
+        self.group = _group_size(groups, e)
         f = lambda *shape: np.zeros(shape, dtype)  # noqa: E731
         i = lambda *shape: np.zeros(shape, np.int32)  # noqa: E731
         b = lambda *shape: np.zeros(shape, bool)  # noqa: E731
@@ -149,7 +183,11 @@ class FleetRef:
         self.cq_dl, self.cq_so, self.cq_rank = f(e, c), b(e, c), f(e, c)
         self.cq_model, self.cq_blocked = i(e, c), b(e, c)
         self.busy_rem = f(e)
-        self.busy_until = f(e, s)            # FaaS slots, free iff <= now
+        # FaaS slots, free iff <= now; an edge's slots past its own count
+        # are never free
+        self.n_slots = n_slots
+        self.busy_until = np.where(np.arange(s) < n_slots[:, None],
+                                   self.c(0), self.c(POS)).astype(dtype)
         self.seq = i(e)
         self.n_success, self.n_miss, self.n_drop = i(e, m), i(e, m), i(e, m)
         self.n_stolen, self.n_edge_exec = i(e, m), i(e, m)
@@ -179,6 +217,7 @@ class FleetRef:
         the edges that have some, and :meth:`_put_rows` writes them back."""
         sub = object.__new__(FleetRef)
         sub.tb, sub.p, sub.ft, sub.M = self.tb, self.p, self.ft, self.M
+        sub.group = self.group
         sub.E, sub.counters = len(rows), self.counters
         for f in _STATE:
             setattr(sub, f, getattr(self, f)[rows])
@@ -202,16 +241,14 @@ class FleetRef:
         # ordering only: widening the key to float32 is exact
         perm = np.lexsort((self.eq_seq, self.eq_key.astype(np.float32), ~v),
                           axis=-1)
-        te_sorted = np.take_along_axis(np.where(v, self.eq_te, self.c(0)),
-                                       perm, -1)
+        te_sorted = _take(np.where(v, self.eq_te, self.c(0)), perm)
         ahead_sorted = np.cumsum(te_sorted, axis=-1, dtype=self.ft) \
             - te_sorted
         wait = np.zeros_like(ahead_sorted)
-        np.put_along_axis(wait, perm, ahead_sorted, -1)
+        wait[np.arange(len(perm))[:, None], perm] = ahead_sorted
         head = perm[:, 0]
         is_head = np.zeros_like(v)
-        np.put_along_axis(is_head, head[:, None],
-                          v.any(-1)[:, None], -1)
+        is_head[np.arange(len(v)), head] = v.any(-1)
         return wait, is_head
 
     def _proj(self, now, busy):
@@ -220,9 +257,8 @@ class FleetRef:
 
     def _pool_wait(self, now):
         pending = (self.cq_valid & ~self.cq_so).sum(-1)
-        k = np.clip(pending, 0, self.p.cloud_slots - 1)
-        kth = np.take_along_axis(np.sort(self.busy_until, -1), k[:, None],
-                                 -1)[:, 0]
+        k = np.clip(pending, 0, self.n_slots - 1)
+        kth = _take(np.sort(self.busy_until, -1), k[:, None])[:, 0]
         return np.maximum(kth - now, self.c(0))
 
     def _t_cur(self, now):
@@ -269,7 +305,7 @@ class FleetRef:
 
     def _occupy(self, now, dispatch, end):
         """Dispatched task k (in slot order) takes the k-th free slot."""
-        s = self.p.cloud_slots
+        s = self.busy_until.shape[1]
         drank = _excl_cumsum(dispatch)
         by_rank = np.zeros((self.E, s + 1), self.ft)
         e_i, c_i = np.nonzero(dispatch)
@@ -277,7 +313,7 @@ class FleetRef:
         free = self.busy_until <= now
         frank = _excl_cumsum(free)
         fill = free & (frank < dispatch.sum(-1, keepdims=True))
-        got = np.take_along_axis(by_rank, np.minimum(frank, s), -1)
+        got = _take(by_rank, np.minimum(frank, s))
         self.busy_until = np.where(fill, got, self.busy_until)
 
     def _free_gate(self, now, want):
@@ -287,15 +323,15 @@ class FleetRef:
     # -- phases ----------------------------------------------------------
     def _resolve_cloud(self, now, theta, bw_pen, cloud_up, link_up, jit_c):
         tb, p, mdl = self.tb, self.p, self.cq_model
-        mature = (self.cq_valid & (self.cq_trig <= now) & cloud_up
-                  & link_up[:, None])
+        mature = (self.cq_valid & (self.cq_trig <= now)
+                  & cloud_up[:, None] & link_up[:, None])
         run = mature & ~self.cq_so
-        fits = now + np.take_along_axis(self.a_cur, mdl, 1) <= self.cq_dl
+        fits = now + _take(self.a_cur, mdl) <= self.cq_dl
         avail = self._free_gate(now, run & fits)
         dispatch = run & fits & avail
         skipped = run & ~fits & avail
         act = (self.c(p.cloud_frac) * tb.t_cloud[mdl]
-               * np.take_along_axis(jit_c, mdl, 1) + theta[:, None]
+               * _take(jit_c, mdl) + theta[:, None]
                + bw_pen[:, None])
         success = dispatch & (now + act <= self.cq_dl)
         util = np.where(success, tb.gamma_c[mdl],
@@ -319,7 +355,7 @@ class FleetRef:
         """Admit a batch of cloud offers against the pre-offer state; the
         accepted fill the free cloud-queue slots in ascending order."""
         tb, p = self.tb, self.p
-        t_hat = np.take_along_axis(t_cur, models, 1)
+        t_hat = _take(t_cur, models)
         feasible = now + t_hat <= dls
         negative = tb.gamma_c[models] <= 0
         trig = np.where(negative, dls - tes,
@@ -336,7 +372,7 @@ class FleetRef:
         def put(old, vals):
             by_rank = np.zeros((self.E, qc + 1), old.dtype)
             by_rank[e_i, arank[e_i, k_i]] = vals[e_i, k_i]
-            got = np.take_along_axis(by_rank, np.minimum(frank, qc), -1)
+            got = _take(by_rank, np.minimum(frank, qc))
             return np.where(fill, got, old)
 
         self.cq_trig = put(self.cq_trig, trig)
@@ -370,7 +406,7 @@ class FleetRef:
 
         def eqn3(models, dls):                               # Eqn 3
             ge, gc = tb.gamma_e[models], tb.gamma_c[models]
-            cf = now + np.take_along_axis(t_cur, models, 1) <= dls
+            cf = now + _take(t_cur, models) <= dls
             return np.where(cf & (gc > 0), ge - gc, ge)
 
         s_vic = np.where(victims, eqn3(self.eq_model, self.eq_dl),
@@ -416,18 +452,23 @@ class FleetRef:
         self.n_drop += self._count(flush, self.eq_model)
         self.eq_valid = self.eq_valid & ~flush
         self._add("drop_crash", flush)
+        # an edge still busy, or with no task queued, does nothing in a
+        # substep; nor does one that did nothing in the substep before
+        acting = (self.eq_valid.any(-1) | self.cq_valid.any(-1))
         for _ in range(self.p.substeps):
-            # an edge still busy does nothing in this substep
-            rows = np.nonzero(self.busy_rem <= 0)[0]
+            rows = np.nonzero(acting & (self.busy_rem <= 0))[0]
+            if not len(rows):
+                break
             sub = self._rows(rows)
-            sub._substep(now, jit_e[rows], edge_up[rows])
+            acting[rows] = sub._substep(now, jit_e[rows], edge_up[rows])
             self._put_rows(rows, sub)
         dt = self.c(self.p.dt)
         self.busy_rem = np.maximum(self.busy_rem - dt, -dt)
 
     def _substep(self, now, jit_e, edge_up):
         """One executor action per edge: a JIT drop of an infeasible head,
-        or a start (a §5.3 steal first, else the head)."""
+        or a start (a §5.3 steal first, else the head).  Returns the edges
+        that acted."""
         tb, p = self.tb, self.p
         rows = np.arange(self.E)
         min_edge_t = tb.t_edge.min()
@@ -486,56 +527,76 @@ class FleetRef:
             np.int32)
         self.qos = self.qos + util
         self._add("edge_exec", start)
+        return drop | start
 
     def _peer_offload(self, now, edge_valid):
         """Between ticks: move the worst-slack exportable task of the most
-        overloaded edge to the least-loaded other edge, per round."""
-        p = self.p
-        e = np.arange(self.E)
+        overloaded edge to the least-loaded other edge of its run, per
+        round and per run."""
+        p, g = self.p, self.group
+        n = self.E // g
+        e = np.arange(g)                          # an edge's place in its run
+        base = np.arange(n) * g
+        valid = edge_valid.reshape(n, g)
         thresh = self.c(p.coop_slack_ms)
         for _ in range(p.coop_rounds):
             busy = np.maximum(self.busy_rem, self.c(0))
             proj, _ = self._proj(now, busy)
             slacks = np.where(self.eq_valid, self.eq_dl - proj, self.c(POS))
             min_slack = np.where(edge_valid, slacks.min(-1), self.c(POS))
+            if not (min_slack < thresh).any():
+                break        # no edge can export, nor in a later round
             load = np.where(edge_valid, busy + np.where(
                 self.eq_valid, self.eq_te, self.c(0)).sum(-1, dtype=self.ft),
-                self.c(POS))
-            lead, best = _masked_arg(load, edge_valid, is_max=False)
-            runner_up = np.where(e == lead, self.c(POS), load).min()
-            dst_load = np.where(e == lead, runner_up, best)
+                self.c(POS)).reshape(n, g)
+            lead, best = _masked_arg(load, valid, is_max=False)
+            is_lead = e == lead[:, None]
+            runner_up = np.where(is_lead, self.c(POS), load).min(-1)
+            dst_load = np.where(is_lead, runner_up[:, None],
+                                best[:, None]).reshape(-1)
             exportable = (self.eq_valid & (slacks < thresh)
                           & (now + dst_load[:, None] + self.eq_te
                              <= self.eq_dl)).any(-1)
-            over = (min_slack < thresh) & exportable & edge_valid
-            sidx, _ = _masked_arg(min_slack, over, is_max=False)
-            src = max(int(sidx), 0)
-            didx, _ = _masked_arg(load, edge_valid & (e != src), is_max=False)
-            dst = max(int(didx), 0)
-            cand = (self.eq_valid[src] & (slacks[src] < thresh)
-                    & (now + load[dst] + self.eq_te[src] <= self.eq_dl[src]))
-            vidx, _ = _masked_arg(slacks[src], cand, is_max=False)
-            free = ~self.eq_valid[dst]
-            if not (over.any() and sidx >= 0 and didx >= 0 and vidx >= 0
-                    and free.any()):
-                continue
-            vi, slot = int(vidx), int(np.argmax(free))
-            self.eq_valid[src, vi] = False
-            self.eq_valid[dst, slot] = True
+            over = ((min_slack < thresh) & exportable
+                    & edge_valid).reshape(n, g)
+            sidx, _ = _masked_arg(min_slack.reshape(n, g), over,
+                                  is_max=False)
+            src = np.maximum(sidx, 0)
+            didx, _ = _masked_arg(load, valid & (e != src[:, None]),
+                                  is_max=False)
+            dst = np.maximum(didx, 0)
+            s_at, d_at = base + src, base + dst
+            cand = (self.eq_valid[s_at] & (slacks[s_at] < thresh)
+                    & (now + load[np.arange(n), dst][:, None]
+                       + self.eq_te[s_at] <= self.eq_dl[s_at]))
+            vidx, _ = _masked_arg(slacks[s_at], cand, is_max=False)
+            free = ~self.eq_valid[d_at]
+            go = (over.any(-1) & (sidx >= 0) & (didx >= 0) & (vidx >= 0)
+                  & free.any(-1))
+            if not go.any():
+                break        # nothing moved, nor will in a later round
+            si, di = s_at[go], d_at[go]
+            vi, slot = vidx[go], np.argmax(free[go], -1)
+            self.eq_valid[si, vi] = False
+            self.eq_valid[di, slot] = True
             for a in (self.eq_key, self.eq_te, self.eq_dl, self.eq_abs,
                       self.eq_model):
-                a[dst, slot] = a[src, vi]
-            self.eq_seq[dst, slot] = self.seq[dst]
-            self.seq[dst] += 1
-            self.n_peer_out[src] += 1
-            self.n_peer_in[dst] += 1
-            self.counters["peer_out"] = self.counters.get("peer_out", 0) + 1
-            self.counters["peer_in"] = self.counters.get("peer_in", 0) + 1
+                a[di, slot] = a[si, vi]
+            self.eq_seq[di, slot] = self.seq[di]
+            self.seq[di] += 1
+            self.n_peer_out[si] += 1
+            self.n_peer_in[di] += 1
+            moved = int(go.sum())
+            self.counters["peer_out"] = self.counters.get("peer_out", 0) \
+                + moved
+            self.counters["peer_in"] = self.counters.get("peer_in", 0) \
+                + moved
 
     # -- one tick --------------------------------------------------------
     def step(self, x: dict) -> dict:
         """Advance one tick; ``x`` holds the tick's signal row
-        (``now``, ``cloud_up`` scalars; ``theta``, ``bw``, ``load_mult``,
+        (``now`` a scalar; ``cloud_up`` a scalar or per edge; ``theta``,
+        ``bw``, ``load_mult``,
         ``valid``, ``edge_up``, ``link_up`` per edge; ``arrive``,
         ``order`` per edge and model; ``exec_jit`` ``[E, M, 2]``).
         Returns the tick's fleet-summed counters and outcome deltas."""
@@ -554,14 +615,16 @@ class FleetRef:
         before = (self.n_success.sum(), self.n_miss.sum(), self.n_drop.sum(),
                   self.n_stolen.sum())
         self.counters = {k: 0 for k in COUNTERS}
-        cloud_up, link_up = bool(x["cloud_up"]), np.asarray(x["link_up"], bool)
+        cloud_up = np.broadcast_to(np.asarray(x["cloud_up"], bool), (self.E,))
+        link_up = np.asarray(x["link_up"], bool)
         # only edges with a matured cloud-queue task have anything to resolve
         rows = np.nonzero((self.cq_valid & (self.cq_trig <= now)).any(-1)
                           & cloud_up & link_up)[0]
-        sub = self._rows(rows)
-        sub._resolve_cloud(now, theta[rows], bw_pen[rows], cloud_up,
-                           link_up[rows], jit[rows, :, 1])
-        self._put_rows(rows, sub)
+        if len(rows):
+            sub = self._rows(rows)
+            sub._resolve_cloud(now, theta[rows], bw_pen[rows],
+                               cloud_up[rows], link_up[rows], jit[rows, :, 1])
+            self._put_rows(rows, sub)
         self.cq_blocked = self.cq_blocked & self.cq_valid
         order = np.asarray(x["order"])
         arrive = np.asarray(x["arrive"], bool)
@@ -570,6 +633,8 @@ class FleetRef:
             mdl = order[:, i].astype(np.int64)
             # an edge with no arrival in this slot is left as it is
             rows = np.nonzero(arrive[np.arange(self.E), mdl])[0]
+            if not len(rows):
+                continue
             sub = self._rows(rows)
             sub._route(now, mdl[rows], np.ones(len(rows), bool),
                        load_mult[rows], edge_up[rows])

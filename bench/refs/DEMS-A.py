@@ -3,7 +3,7 @@ edges independent of one another."""
 from harness import ref_fleet
 
 
-def make(cfg: dict, n_edges: int, dtype):
+def make(cfg: dict, n_edges: int, dtype, **lanes):
     return ref_fleet.FleetRef(ref_fleet.Table(cfg["models"], dtype),
                               ref_fleet.Params.from_config(cfg, coop=False),
-                              n_edges, dtype)
+                              n_edges, dtype, **lanes)

@@ -6,9 +6,9 @@
 
 Everything a cell is made of is found by name: the cell in
 ``BENCHMARK.json``, its configuration file, its traffic file under
-``bench/traffic/`` (whose ``entry`` picks ``bench/harness/replay.py`` or
-``bench/harness/live.py``), and one reader per per-layer metric under
-``bench/metrics/``.  ``--trace 0`` prints the cell's end-to-end metrics,
+``bench/traffic/`` (whose ``entry`` picks ``bench/harness/<entry>.py``:
+``replay``, ``live`` or ``sweep``), and one reader per per-layer metric
+under ``bench/metrics/``.  ``--trace 0`` prints the cell's end-to-end metrics,
 ``--trace 1`` its per-layer metrics, read from a profiler trace of the
 same window.  Every line but the last is information; the numbers
 compared for ``correct`` are the last lines on standard error and the
@@ -51,7 +51,8 @@ sys.path.insert(0, str(BENCH))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from harness import check, gen, trace as trace_lib  # noqa: E402
+from harness import check, gen, program_trace  # noqa: E402
+from harness import trace as trace_lib  # noqa: E402
 
 
 class NoChip(Exception):
@@ -137,6 +138,7 @@ class Cell:
         self.memory_peak = None
         self.setup_compile_s = None
         self.trace_dir = None
+        self._traced = None
         self._say = say
 
     # -- what entries call -------------------------------------------------
@@ -176,19 +178,39 @@ class Cell:
         w = Window(self)
         if self.trace:
             self.trace_dir = tempfile.mkdtemp(prefix="bench-trace-")
-            jax.profiler.start_trace(self.trace_dir)
+            # no reader reads the Python tracer's function events, and
+            # they slow the host code of the window
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+            self._traced = w.span("bench.window")
+            self._traced.__enter__()
         try:
-            with CompileCounter() as cc, w.span("bench.window"):
+            with CompileCounter() as cc:
                 w.t0 = time.perf_counter()
                 self.metric("setup_s", w.t0 - self.proc_t0, "s")
                 yield w
                 w.seconds = time.perf_counter() - w.t0
         finally:
-            if self.trace:
-                jax.profiler.stop_trace()
+            self.stop_trace()
         self.layer["window_s"] = w.seconds
         self.say(f"window: {w.seconds:.6f} s, {cc.count} backend compiles "
                  f"inside it ({cc.total_secs:.6f} s)")
+
+    def stop_trace(self) -> None:
+        """End the traced part of the window (its ``bench.window`` span)
+        and stop the profiler: at the window's end, or sooner where an
+        entry's window holds more device events than a profile can keep.
+        The measured window goes on; the trace's window is what was
+        traced."""
+        if self._traced is None:
+            return
+        self._traced.__exit__(None, None, None)
+        self._traced = None
+        t0 = time.perf_counter()
+        jax.profiler.stop_trace()
+        self.say(f"trace: profiler stopped in "
+                 f"{time.perf_counter() - t0:.3f} s")
 
     def metric(self, name: str, value: float, unit: str) -> None:
         self.metrics[name] = {"value": float(value), "unit": unit}
@@ -248,7 +270,7 @@ def run_cell(cell: Cell, dev: dict) -> dict:
     device = dict(dev, memory_peak_bytes=cell.memory_peak)
     if cell.trace:
         t0 = time.perf_counter()
-        tr = trace_lib.load(cell.trace_dir)
+        tr = program_trace.load(cell.trace_dir)
         shutil.rmtree(cell.trace_dir, ignore_errors=True)
         cell.say(f"trace: {sum(len(v) for v in tr.devices.values())} device "
                  f"operations read in {time.perf_counter() - t0:.3f} s")

@@ -62,6 +62,21 @@ def test_existing_readers_read_as_before(name, metric):
     assert got == (None if want is None else pytest.approx(want, rel=1e-12))
 
 
+@pytest.mark.parametrize("name,metric", [
+    (f.name, m.stem) for f in sorted(FIX.glob("trace_steady*.json"))
+    for m in sorted((FIX.parents[2] / "bench" / "metrics").glob("*.py"))])
+def test_every_reader_reads_alike_from_either_loader(name, metric):
+    """``bench/run.py`` loads a traced run with ``program_trace.load``:
+    every reader reads from its ``ProgramTrace`` what it reads from the
+    plain ``Trace``, on every recorded fixture."""
+    ctx = dict(layer=LAYER, peak={"hbm_bytes_per_s": 819e9},
+               setup_compile_s=1.5, chips=1)
+    got = reader(metric).read(dict(ctx, trace=load(name)))
+    plain = reader(metric).read(
+        dict(ctx, trace=T.Trace.from_json(str(FIX / name))))
+    assert got == plain
+
+
 @pytest.fixture(scope="module")
 def controller_trace(tmp_path_factory):
     """A 4-edge controller stepped on the CPU under the profiler: six

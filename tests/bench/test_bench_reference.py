@@ -76,3 +76,36 @@ def test_control_fails_the_live_limits():
     assert check.record_mismatch_pct(got_rec, want_rec) \
         > limits["record_mismatch_pct"]
     assert check.mismatch_pct(got, want, arrived) > limits["mismatch_pct"]
+
+
+@pytest.mark.parametrize("policy", ["DEMS-A", "DEMS-A-COOP"])
+def test_stacked_runs_equal_runs_stepped_alone(policy):
+    """Independent runs stacked in one reference, each run with its own
+    FaaS slot count and its own peer exchange, end as each run stepped
+    alone; the 2-slot runs end otherwise than with 16 slots."""
+    runs, edges, slots = 3, 4, [2, 16, 2]
+    t = traffic("hotspot-4da-2da", policy=policy, horizon_ms=12_000.0,
+                drones_per_edge=3, hot_fraction=0.25, hot_drones_per_edge=6)
+    sig = jax.device_get(gen.replay_signals(
+        gen.seed_key(SEED), t, runs * edges, len(CFG["models"]), DT))
+    stacked = check.reference(CFG, policy, runs * edges,
+                              slots=np.repeat(slots, edges),
+                              groups=np.repeat(np.arange(runs), edges))
+    alone = [check.reference(CFG, policy, edges, slots=s) for s in slots]
+    roomy = check.reference(CFG, policy, edges)           # 16 slots
+    for k in range(sig["times"].shape[0]):
+        x = {f: v[k] for f, v in sig.items()}
+        x["now"] = x.pop("times")
+        stacked.step(x)
+        for r, ref in enumerate(alone + [roomy]):
+            lo = (r % runs) * edges
+            ref.step({f: v if np.ndim(v) == 0 else v[lo:lo + edges]
+                      for f, v in x.items()})
+    got = stacked.outcome()
+    for f in check.FIELDS:
+        want = np.concatenate([ref.outcome()[f] for ref in alone])
+        np.testing.assert_array_equal(got[f], want, err_msg=f)
+    assert any((alone[0].outcome()[f] != roomy.outcome()[f]).any()
+               for f in check.FIELDS)
+    if policy.endswith("-COOP"):
+        assert (got["n_peer_out"].reshape(runs, edges).sum(1) > 0).all()

@@ -49,12 +49,14 @@ def test_names_units_and_keys():
 def test_cell_resolves_by_name(cell):
     """Configuration, traffic and every per-layer reader exist, and each
     per-layer metric's cell reports the end-to-end metric it moves."""
+    import importlib
+
     R = bench_run()
     res = R.resolve(SPEC, cell)
     assert res["cfg"]["name"] == res["cell"]["config"]
-    assert res["traffic"]["entry"] in ("replay", "live")
-    assert (ROOT / "bench" / "harness" /
-            f"{res['traffic']['entry']}.py").is_file()
+    entry = res["traffic"]["entry"]
+    assert (ROOT / "bench" / "harness" / f"{entry}.py").is_file()
+    assert callable(importlib.import_module(f"harness.{entry}").run)
     e2e = {m["name"] for m in res["e2e"]}
     assert "setup_s" in e2e and len(e2e) >= 2
     assert res["layers"], "every cell reports a per-layer metric"
@@ -115,12 +117,16 @@ def test_new_traffic_and_metric_files_are_picked_up(tmp_path):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_policy_has_a_reference_file(cell):
-    """Each cell's policy finds its plain reference by name."""
+    """Each of a cell's policies finds its plain reference by name."""
     from harness import check
 
     res = bench_run().resolve(SPEC, cell)
-    ref = check.reference(res["cfg"], res["traffic"]["policy"], 2)
-    assert callable(ref.step) and callable(ref.outcome)
+    t = res["traffic"]
+    policies = t["policies"] if "policies" in t else [t["policy"]]
+    assert policies
+    for policy in policies:
+        ref = check.reference(res["cfg"], policy, 2)
+        assert callable(ref.step) and callable(ref.outcome)
 
 
 def test_unknown_policy_has_no_reference():
