@@ -1,6 +1,6 @@
-"""Profiling hooks: ``jax.profiler`` capture and compile accounting.
+"""Profiling hooks: ``jax.profiler`` capture, compile and sweep accounting.
 
-Two concerns, both about the *compiled program*, not the scheduler:
+Three concerns, all about the *compiled program*, not the scheduler:
 
 * :func:`profile_trace` — a context manager around
   ``jax.profiler.trace`` that captures a TensorBoard/Perfetto-readable
@@ -14,11 +14,16 @@ Two concerns, both about the *compiled program*, not the scheduler:
   policy data into a static argument shows up here as extra traces —
   ``tests/conftest.py``'s ``compile_guard`` fixture turns that into a
   test failure.
+* :class:`SweepStats` — where a registry sweep's wall time went: the
+  record :func:`repro.scenarios.runner.run_registry_sweep` fills when a
+  caller passes one (its lowering, each bucket's lanes, chips, ticks,
+  run and gather, and ``summarize``).
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+from typing import NamedTuple
 
 import jax
 
@@ -72,6 +77,43 @@ class CompileCounter:
 
     def __exit__(self, *exc) -> None:
         jax.monitoring.unregister_event_duration_listener(self._listen)
+
+
+class SweepBucket(NamedTuple):
+    """One bucket of one sweep pass."""
+
+    lanes: int           # replicas stacked in the bucket
+    chips: int           # devices its replica axis fanned over
+    ticks: int           # ticks of its mission
+    run_s: float         # ``run_batch`` dispatch until the state is ready
+    gather_s: float      # the end state's ``jax.device_get`` to the host
+
+
+@dataclasses.dataclass
+class SweepStats:
+    """What ``run_registry_sweep(..., stats=record)`` did, summed over
+    every pass the record was given to.
+
+    Host-clock seconds.  Filling it costs one ``block_until_ready`` a
+    bucket (so ``run_s`` and ``gather_s`` part the device's work from
+    the transfer); the rows are the same with and without it.
+    """
+
+    lower_s: float = 0.0          # compile_registry_groups
+    summarize_s: float = 0.0      # end states to rows
+    buckets: list[SweepBucket] = dataclasses.field(default_factory=list)
+
+    @property
+    def run_s(self) -> float:
+        return sum(b.run_s for b in self.buckets)
+
+    @property
+    def gather_s(self) -> float:
+        return sum(b.gather_s for b in self.buckets)
+
+    @property
+    def bucket_ticks(self) -> int:
+        return sum(b.ticks for b in self.buckets)
 
 
 @dataclasses.dataclass

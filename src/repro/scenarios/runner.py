@@ -12,9 +12,11 @@ single compiled program (one jit instead of R Python-loop jits).
 from __future__ import annotations
 
 import dataclasses
+import time
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.schedulers import make_policy
 from repro.scenarios.compile import (compile_exec_jitter, compile_fleet,
@@ -232,7 +234,7 @@ def run_scenario_fleet_batch(spec: ScenarioSpec, policy,
 def run_registry_sweep(scenarios=None, policies=("DEMS",), seeds=(0,), *,
                        dt: float = 25.0, duration_ms: float | None = None,
                        mesh=None, trace=None, planner: str = "bucketed",
-                       donate: bool = False) -> list[dict]:
+                       donate: bool = False, stats=None) -> list[dict]:
     """Scenarios × policies × seeds as compiled sweep programs.
 
     ``planner`` picks the lowering — both produce bitwise-identical
@@ -267,8 +269,15 @@ def run_registry_sweep(scenarios=None, policies=("DEMS",), seeds=(0,), *,
     edge-flattened lowering concatenated back along the edge axis; under
     the padded planner the model axis stays padded to the batch maximum,
     padded models simply never count).
+
+    ``stats`` (a :class:`repro.obs.prof.SweepStats`) is filled with the
+    pass's lowering, each bucket's lanes, chips, ticks, run and gather
+    seconds, and ``summarize``; only then is each bucket's state waited
+    for before its gather.  The ``fleet.sweep.*`` profiler spans mark
+    the same phases (``docs/OBSERVABILITY.md``).
     """
     from repro.launch.mesh import make_mesh
+    from repro.obs.prof import SweepBucket
     from repro.scenarios.compile import (compile_registry_batch,
                                          compile_registry_groups)
     from repro.sim.fleet_jax import FleetResult, run_batch
@@ -310,34 +319,52 @@ def run_registry_sweep(scenarios=None, policies=("DEMS",), seeds=(0,), *,
         return make_mesh((n,), ("replica",)) if n > 1 else None
 
     if planner == "bucketed":
-        by_key = {}
-        for batch, rows in compile_registry_groups(
-                scenarios, policies, seeds, dt=dt, duration_ms=duration_ms):
-            # one host transfer per bucket: the per-row lane slicing in
-            # summarize would otherwise issue a device gather per leaf
-            # per run (slow when the replica axis is sharded)
-            res = jax.device_get(run_batch(
-                batch, dt=dt, mesh=auto_mesh(batch) if auto else mesh,
-                trace=trace, donate=donate))
-            for d in summarize(res, rows):
-                by_key[d["scenario"], d["policy"], d["seed"]] = d
-        from repro.scenarios.registry import names
-        order = tuple(sc if isinstance(sc, str) else sc.name
-                      for sc in scenarios) if scenarios is not None \
-            else names()
-        return [by_key[sc, pol, seed]
-                for sc in order for pol in policies for seed in seeds]
-    if planner != "padded":
+        lower = compile_registry_groups
+    elif planner == "padded":
+        def lower(*a, **k):
+            return [compile_registry_batch(*a, **k)]
+    else:
         raise ValueError(f"unknown planner {planner!r}; "
                          f"choose 'bucketed' or 'padded'")
 
-    batch, rows = compile_registry_batch(scenarios, policies, seeds,
-                                         dt=dt, duration_ms=duration_ms)
-    if auto:
-        mesh = auto_mesh(batch)
-    res = jax.device_get(run_batch(batch, dt=dt, mesh=mesh, trace=trace,
-                                   donate=donate))
-    return summarize(res, rows)
+    t0 = time.perf_counter()
+    with TraceAnnotation("fleet.sweep.lower"):
+        groups = lower(scenarios, policies, seeds, dt=dt,
+                       duration_ms=duration_ms)
+    if stats is not None:
+        stats.lower_s += time.perf_counter() - t0
+    by_key = {}
+    for i, (batch, rows) in enumerate(groups):
+        lanes, ticks = (int(n) for n in batch.signals.arrive.shape[:2])
+        m = auto_mesh(batch) if auto else mesh
+        chips = 1 if m is None else int(m.devices.size)
+        t0 = time.perf_counter()
+        with TraceAnnotation("fleet.sweep.bucket", bucket=i, lanes=lanes,
+                             chips=chips, ticks=ticks):
+            out = run_batch(batch, dt=dt, mesh=m, trace=trace,
+                            donate=donate)
+            if stats is not None:
+                out = jax.block_until_ready(out)
+        t1 = time.perf_counter()
+        # one host transfer per bucket: the per-row lane slicing in
+        # summarize would otherwise issue a device gather per leaf per
+        # run (slow when the replica axis is sharded)
+        with TraceAnnotation("fleet.sweep.gather", bucket=i):
+            res = jax.device_get(out)
+        t2 = time.perf_counter()
+        with TraceAnnotation("fleet.sweep.summarize", bucket=i):
+            for d in summarize(res, rows):
+                by_key[d["scenario"], d["policy"], d["seed"]] = d
+        if stats is not None:
+            stats.buckets.append(
+                SweepBucket(lanes, chips, ticks, t1 - t0, t2 - t1))
+            stats.summarize_s += time.perf_counter() - t2
+    from repro.scenarios.registry import names
+    order = tuple(sc if isinstance(sc, str) else sc.name
+                  for sc in scenarios) if scenarios is not None \
+        else names()
+    return [by_key[sc, pol, seed]
+            for sc in order for pol in policies for seed in seeds]
 
 
 def fleet_summary(final) -> dict[str, float]:
